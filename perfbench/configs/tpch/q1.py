@@ -1,0 +1,130 @@
+"""TPC-H Q1, pricing summary report (Clause 2.4.1), over lineitem.
+
+Filter l_shipdate <= cutoff, group by (l_returnflag, l_linestatus), sum
+quantity, price, discounted price and charge, average quantity, price
+and discount, count; order by the two keys. Money and its products stay
+exact int64 (DECIMAL(15,2) scaled to cents and percents); the averages
+are FLOAT64.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, List, Sequence
+
+import numpy as np
+
+from . import gen
+
+WITH_STATS = False
+INT_FIELDS = (0, 1, 2, 3, 4, 5, 9)
+MEAN_FIELDS = (6, 7, 8)
+# mean_rel_err: sound runs on TPU v5e read at most 1.49e-14 over 15
+# seeds; the float32 control reads at least 1.02e-7 over 6 seeds at SF1
+# and SF10. The limit sits between, with more room above the lower.
+LIMITS = {"wrong_values": 0, "mean_rel_err": 1e-10}
+
+
+def generate(rows: Dict[str, int], seed: int) -> List[List[np.ndarray]]:
+    return gen.lineitem_q1(rows, seed)
+
+
+def plan(cutoff: int):
+    """filter -> project -> groupby -> sort, as the engine's own TPC-H
+    benchmark writes it."""
+    from spark_rapids_jni_tpu.plan import (Filter, GroupBy, Project, Scan,
+                                           Sort, col, i64, lit)
+    filt = Filter(Scan(7), col(6) <= lit(cutoff))
+    disc_price = i64(col(1)) * (lit(100) - i64(col(2)))
+    charge = disc_price * (lit(100) + i64(col(3)))
+    proj = Project(filt, (
+        col(4), col(5),                  # returnflag, linestatus keys
+        i64(col(0)),                     # qty
+        i64(col(1)),                     # price
+        disc_price, charge,
+        i64(col(2)),                     # disc
+    ))
+    gb = GroupBy(proj, (0, 1),
+                 ((2, "sum"), (3, "sum"), (4, "sum"), (5, "sum"),
+                  (2, "mean"), (3, "mean"), (6, "mean"), (2, "count")))
+    return Sort(gb, (0, 1))
+
+
+def input_rows(rows: Dict[str, int]) -> int:
+    return rows["lineitem"]
+
+
+def bytes_read(rows: Dict[str, int]) -> int:
+    """All seven columns: two int64, five int32."""
+    return rows["lineitem"] * (2 * 8 + 5 * 4)
+
+
+def answer(cols: Sequence[np.ndarray]) -> list:
+    """Rows of (rf, ls, 4 sums, 3 means, count); FLOAT64 columns hold
+    IEEE bit patterns."""
+    out = []
+    for i in range(cols[0].shape[0]):
+        row = [int(cols[j][i]) for j in range(10)]
+        for j in MEAN_FIELDS:
+            row[j] = float(cols[j][i:i + 1].view(np.float64)[0])
+        out.append(tuple(row))
+    return out
+
+
+def reference(tables, cutoff: int) -> list:
+    qty, price, disc, tax, rf, ls, sd = tables[0]
+    keep = sd <= cutoff
+    dp = price * (100 - disc.astype(np.int64))
+    charge = dp * (100 + tax.astype(np.int64))
+    rows = []
+    for r in range(3):
+        for s in range(2):
+            m = keep & (rf == r) & (ls == s)
+            cnt = int(m.sum())
+            if not cnt:
+                continue
+            sq, sp = int(qty[m].sum()), int(price[m].sum())
+            sd_ = int(disc[m].astype(np.int64).sum())
+            rows.append((r, s, sq, sp, int(dp[m].sum()),
+                         int(charge[m].sum()), sq / cnt, sp / cnt,
+                         sd_ / cnt, cnt))
+    return rows
+
+
+def control(tables, cutoff: int) -> list:
+    """``reference`` with every product, sum and mean in float32."""
+    import jax.numpy as jnp
+    f = jnp.float32
+    qty, price, disc, tax, rf, ls, sd = (jnp.asarray(a) for a in tables[0])
+    keep = sd <= cutoff
+    dp = price.astype(f) * (100 - disc).astype(f)
+    charge = dp * (100 + tax).astype(f)
+    rows = []
+    for r in range(3):
+        for s in range(2):
+            m = keep & (rf == r) & (ls == s)
+            cnt = float(jnp.sum(m.astype(f)))
+            if not cnt:
+                continue
+
+            def fsum(x):
+                return float(jnp.sum(jnp.where(m, x.astype(f), f(0))))
+            sq, sp, sd_ = fsum(qty), fsum(price), fsum(disc)
+            rows.append((r, s, round(sq), round(sp), round(fsum(dp)),
+                         round(fsum(charge)), sq / cnt, sp / cnt,
+                         sd_ / cnt, round(cnt)))
+    return rows
+
+
+def compare(got: list, want: list) -> Dict[str, float]:
+    """wrong_values: integer fields that differ, every field of a missing
+    or extra row counted; mean_rel_err: the largest relative error of a
+    mean over the rows both sides hold."""
+    wrong = 10 * abs(len(got) - len(want))
+    rel = 0.0
+    for g, w in zip(got, want):
+        wrong += sum(g[j] != w[j] for j in INT_FIELDS)
+        for j in MEAN_FIELDS:
+            e = abs(g[j] - w[j]) / max(abs(w[j]), 1e-300)
+            if not e <= rel:  # a NaN stays
+                rel = e
+    return {"wrong_values": wrong, "mean_rel_err": rel}
