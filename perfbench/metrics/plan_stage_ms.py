@@ -1,0 +1,18 @@
+"""The engine's ``stage_s``, the self time of ``plan.stage``: the
+sharded executor's layout and commit of the input leaves to the mesh; in
+ms per untraced query. The engine adds to the counter once a query (once
+an attempt where the fault ladder replays on a smaller mesh, which no
+cell does) and keeps its latest additions (``plan_metrics.recent``): the
+mean of the last as many as the window has untraced queries, which come
+last, so the warm-up's compiles stay out. Nothing where the engine keeps
+no such counter or added none."""
+
+
+def read(run):
+    from spark_rapids_jni_tpu.plan import plan_metrics
+    recent = getattr(plan_metrics, "recent", None)
+    n = run["measured"]["queries"]
+    if recent is None or not n:
+        return None
+    vals = recent("stage_s", n)
+    return 1000.0 * sum(vals) / len(vals) if vals else None

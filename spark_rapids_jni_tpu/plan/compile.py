@@ -28,10 +28,11 @@ __init__) makes the miss path a disk hit across process restarts.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import re
 import threading
-import time
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,9 +47,10 @@ from ..ops.sort import (gather, select_topk_core, sort_lanes,
                         stable_lexsort)
 from ..utils import config
 from ..utils.shapes import bucket_size
+from ..utils.tracing import timed_range
 from . import expr as ex
 from .nodes import (Filter, GroupBy, Join, Limit, PlanError, PlanNode,
-                    Project, Scan, Sort, fingerprint, linearize)
+                    Project, Scan, Sort, fingerprint, linearize, walk)
 
 
 class PlanMetrics:
@@ -61,7 +63,9 @@ class PlanMetrics:
                  "plan_executes", "plan_fallbacks", "plan_join_fallbacks",
                  "plan_overflows", "plan_oom_retries", "plan_oom_splits",
                  "plan_oom_pieces", "plan_oom_spill_bytes")
-    _TIMES = ("compile_s", "execute_s")
+    _TIMES = ("compile_s", "execute_s", "prepare_s", "dispatch_s",
+              "stage_s", "trim_s")
+    RECENT = 512  # additions kept per time counter, for ``recent``
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -71,6 +75,8 @@ class PlanMetrics:
         with self._lock:
             self._c = {k: 0 for k in self._COUNTERS}
             self._t = {k: 0.0 for k in self._TIMES}
+            self._recent = {k: collections.deque(maxlen=self.RECENT)
+                            for k in self._TIMES}
             self._reasons: Dict[str, int] = {}
 
     def inc(self, name: str, by: int = 1) -> None:
@@ -87,6 +93,16 @@ class PlanMetrics:
     def add_time(self, name: str, seconds: float) -> None:
         with self._lock:
             self._t[name] += seconds
+            self._recent[name].append(seconds)
+
+    def recent(self, name: str, n: int) -> List[float]:
+        """The last ``n`` additions to a time counter, oldest first (at
+        most ``RECENT``). The plan-path phases add once per query, so
+        these are the latest queries' own times, with no earlier warm-up
+        or compile averaged in."""
+        with self._lock:
+            kept = list(self._recent[name])
+        return kept[-n:] if n > 0 else []
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
@@ -213,6 +229,18 @@ def _lower_groupby(node: GroupBy, cols, mask, n: int, dec,
     return out, live_mask, G, False, bad, slot_keys
 
 
+HEAD_SCOPE = "plan.head"
+
+
+def node_scope(pos: int, node: PlanNode,
+               strategy: Optional[str] = None) -> str:
+    """The name scope of one plan node's operations in a fused program:
+    ``plan.<pos>.<Kind>[.<strategy>]``, ``pos`` the node's place in
+    ``walk(plan)`` and ``strategy`` the lowering the planner chose."""
+    name = f"plan.{pos}.{type(node).__name__}"
+    return name if strategy is None else f"{name}.{strategy}"
+
+
 def _make_fn(plan: PlanNode, max_groups: int, out_info: Dict[str, Any],
              decisions=None):
     """Build the traceable whole-plan function. Static facts about the
@@ -225,7 +253,7 @@ def _make_fn(plan: PlanNode, max_groups: int, out_info: Dict[str, Any],
     nodes = linearize(plan)
     after = {id(a): b for a, b in zip(nodes, nodes[1:])}
 
-    def fn(cols: Tuple[Column, ...]):
+    def plan_fused(cols: Tuple[Column, ...]):
         scan = nodes[0]
         assert isinstance(scan, Scan)
         if len(cols) != scan.ncols:
@@ -237,56 +265,67 @@ def _make_fn(plan: PlanNode, max_groups: int, out_info: Dict[str, Any],
         live = None                     # device i32; None while mask is None
         prefix = True                   # trivially true with no mask
         overflow = jnp.asarray(False)
-        for node in nodes[1:]:
-            if isinstance(node, Filter):
-                keep = ex.predicate_mask(ex.eval_expr(node.predicate, cols))
-                mask = keep if mask is None else mask & keep
-                live = jnp.sum(mask, dtype=jnp.int32)
-                prefix = False
-            elif isinstance(node, Project):
-                cols = [ex.project_column(e, cols, n) for e in node.exprs]
-            elif isinstance(node, GroupBy):
+        for pos, node in enumerate(nodes[1:], 1):
+            dec = None
+            if isinstance(node, GroupBy):
                 dec = decisions.of(node) if decisions is not None else None
                 if dec is not None and dec.strategy == "direct_wide" \
                         and isinstance(after.get(id(node)), Limit):
                     dec = None  # Limit needs the generic prefix layout
-                cols, mask, n, prefix, ov, _ = _lower_groupby(
-                    node, cols, mask, n, dec, max_groups)
-                overflow = overflow | ov
-                live = jnp.sum(mask, dtype=jnp.int32)
-            elif isinstance(node, Sort):
-                keys = [cols[i] for i in node.keys]
-                lanes = sort_lanes(keys, node.ascending, node.nulls_first)
-                if mask is not None:
-                    # dead lane LAST == most significant: live rows first
-                    lanes.append((~mask).astype(jnp.uint8))
-                order = stable_lexsort(lanes)
-                cols = [gather(c, order) for c in cols]
-                if mask is not None:
-                    mask = jnp.take(mask, order)
-                prefix = True
-            elif isinstance(node, Limit):
-                if mask is not None and not prefix:
+            strategy = ((dec.strategy if dec is not None else "generic")
+                        if isinstance(node, GroupBy) else None)
+            with jax.named_scope(node_scope(pos, node, strategy)):
+                if isinstance(node, Filter):
+                    keep = ex.predicate_mask(
+                        ex.eval_expr(node.predicate, cols))
+                    mask = keep if mask is None else mask & keep
+                    live = jnp.sum(mask, dtype=jnp.int32)
+                    prefix = False
+                elif isinstance(node, Project):
+                    cols = [ex.project_column(e, cols, n)
+                            for e in node.exprs]
+                elif isinstance(node, GroupBy):
+                    cols, mask, n, prefix, ov, _ = _lower_groupby(
+                        node, cols, mask, n, dec, max_groups)
+                    overflow = overflow | ov
+                    live = jnp.sum(mask, dtype=jnp.int32)
+                elif isinstance(node, Sort):
+                    keys = [cols[i] for i in node.keys]
+                    lanes = sort_lanes(keys, node.ascending,
+                                       node.nulls_first)
+                    if mask is not None:
+                        # dead lane LAST == most significant: live first
+                        lanes.append((~mask).astype(jnp.uint8))
+                    order = stable_lexsort(lanes)
+                    cols = [gather(c, order) for c in cols]
+                    if mask is not None:
+                        mask = jnp.take(mask, order)
+                    prefix = True
+                elif isinstance(node, Limit):
+                    if mask is not None and not prefix:
+                        raise PlanError(
+                            "Limit needs prefix-compacted rows — place it "
+                            "after a Sort or GroupBy, not directly on a "
+                            "Filter")
+                    k = min(node.count, n)
+                    cols = [_slice_col(c, k) for c in cols]
+                    if mask is not None:
+                        mask = mask[:k]
+                        live = jnp.minimum(live, jnp.int32(k))
+                    n = k
+                else:
                     raise PlanError(
-                        "Limit needs prefix-compacted rows — place it "
-                        "after a Sort or GroupBy, not directly on a "
-                        "Filter")
-                k = min(node.count, n)
-                cols = [_slice_col(c, k) for c in cols]
-                if mask is not None:
-                    mask = mask[:k]
-                    live = jnp.minimum(live, jnp.int32(k))
-                n = k
-            else:
-                raise PlanError(f"unknown plan node {type(node).__name__}")
+                        f"unknown plan node {type(node).__name__}")
         out_info["has_mask"] = mask is not None
         out_info["prefix"] = prefix
         out_info["n_out"] = n
-        live_out = jnp.int32(n) if live is None else live.astype(jnp.int32)
-        head = jnp.stack([live_out, overflow.astype(jnp.int32)])
+        with jax.named_scope(HEAD_SCOPE):
+            live_out = (jnp.int32(n) if live is None
+                        else live.astype(jnp.int32))
+            head = jnp.stack([live_out, overflow.astype(jnp.int32)])
         return tuple(cols), mask, head
 
-    return fn
+    return plan_fused
 
 
 @dataclasses.dataclass
@@ -348,16 +387,26 @@ def _make_dag_fn(plan: PlanNode, decisions, max_groups: int,
     code-remap array per cross-dictionary join, in ``dict_joins``
     iteration order."""
     aux_pos = {jid: i for i, jid in enumerate(decisions.dict_joins)}
+    walk_pos = {id(n): i for i, n in enumerate(walk(plan))}
 
-    def fn(tables: Tuple[Tuple[Column, ...], ...],
-           aux: Tuple[jnp.ndarray, ...]):
+    def scope(node) -> str:
+        dec = decisions.of(node)
+        if isinstance(node, GroupBy):
+            strategy = dec.strategy if dec is not None else "generic"
+        elif isinstance(node, (Join, Sort, Limit)) and dec is not None:
+            strategy = dec.strategy
+        else:
+            strategy = None
+        return node_scope(walk_pos[id(node)], node, strategy)
+
+    def plan_dag(tables: Tuple[Tuple[Column, ...], ...],
+                 aux: Tuple[jnp.ndarray, ...]):
         overflow = [jnp.asarray(False)]
         # per-join build context for FD reprobe at GroupBy lowering
         join_env: Dict[int, Dict[str, Any]] = {}
 
-        def lower_join(node: Join) -> _DagState:
-            ls = rec(node.left)
-            rs = rec(node.right)
+        def lower_join(node: Join, ls: _DagState,
+                       rs: _DagState) -> _DagState:
             dec = decisions.of(node)
             lkey = ls.cols[node.left_on[0]]
             rkey = rs.cols[node.right_on[0]]
@@ -417,8 +466,7 @@ def _make_dag_fn(plan: PlanNode, decisions, max_groups: int,
             idx = slot_keys - lo
             return jnp.clip(idx, 0, rn - 1).astype(jnp.int32)
 
-        def lower_groupby(node: GroupBy) -> _DagState:
-            st = rec(node.child)
+        def lower_groupby(node: GroupBy, st: _DagState) -> _DagState:
             dec = decisions.of(node)
             cols, m, G, prefix, bad, slot_keys = _lower_groupby(
                 node, st.cols, st.mask, st.n, dec, max_groups)
@@ -432,6 +480,8 @@ def _make_dag_fn(plan: PlanNode, decisions, max_groups: int,
             return _DagState(cols, m, G, prefix)
 
         def rec(node) -> _DagState:
+            """Lower the inputs of ``node``, then the node itself under
+            its own name scope (scopes never nest)."""
             if isinstance(node, Scan):
                 cols = list(tables[node.input_index])
                 if len(cols) != node.ncols:
@@ -439,26 +489,37 @@ def _make_dag_fn(plan: PlanNode, decisions, max_groups: int,
                                     f"for input {node.input_index}, got "
                                     f"{len(cols)}")
                 return _DagState(cols, None, cols[0].size, True)
+            dec = decisions.of(node)
+            if isinstance(node, Sort) and dec is not None \
+                    and dec.strategy == "skip":
+                return rec(node.child)  # folded into Limit topk
+            topk = (isinstance(node, Limit) and dec is not None
+                    and dec.strategy == "topk")
+            if isinstance(node, Join):
+                inputs = (rec(node.left), rec(node.right))
+            elif topk:
+                inputs = (rec(node.child.child),)
+            else:
+                inputs = (rec(node.child),)
+            with jax.named_scope(scope(node)):
+                return lower(node, dec, topk, *inputs)
+
+        def lower(node, dec, topk: bool, st: _DagState,
+                  rs: Optional[_DagState] = None) -> _DagState:
             if isinstance(node, Filter):
-                st = rec(node.child)
                 keep = ex.predicate_mask(
                     ex.eval_expr(node.predicate, st.cols))
                 m = keep if st.mask is None else st.mask & keep
                 return _DagState(st.cols, m, st.n, False)
             if isinstance(node, Project):
-                st = rec(node.child)
                 cols = [ex.project_column(e, st.cols, st.n)
                         for e in node.exprs]
                 return _DagState(cols, st.mask, st.n, st.prefix)
             if isinstance(node, Join):
-                return lower_join(node)
+                return lower_join(node, st, rs)
             if isinstance(node, GroupBy):
-                return lower_groupby(node)
+                return lower_groupby(node, st)
             if isinstance(node, Sort):
-                dec = decisions.of(node)
-                if dec is not None and dec.strategy == "skip":
-                    return rec(node.child)  # folded into Limit topk
-                st = rec(node.child)
                 keys = [st.cols[i] for i in node.keys]
                 lanes = sort_lanes(keys, node.ascending, node.nulls_first)
                 if st.mask is not None:
@@ -469,10 +530,8 @@ def _make_dag_fn(plan: PlanNode, decisions, max_groups: int,
                      if st.mask is not None else None)
                 return _DagState(cols, m, st.n, True)
             if isinstance(node, Limit):
-                dec = decisions.of(node)
-                if dec is not None and dec.strategy == "topk":
+                if topk:
                     sort_node = node.child
-                    st = rec(sort_node.child)
                     keys = [st.cols[i] for i in sort_node.keys]
                     lanes = sort_lanes(keys, sort_node.ascending,
                                        sort_node.nulls_first)
@@ -485,7 +544,6 @@ def _make_dag_fn(plan: PlanNode, decisions, max_groups: int,
                         jnp.sum(livem, dtype=jnp.int32), jnp.int32(k))
                     m = jnp.arange(k, dtype=jnp.int32) < nlive
                     return _DagState(cols, m, k, True)
-                st = rec(node.child)
                 if st.mask is not None and not st.prefix:
                     raise PlanError(
                         "Limit needs prefix-compacted rows — place it "
@@ -501,12 +559,87 @@ def _make_dag_fn(plan: PlanNode, decisions, max_groups: int,
         out_info["has_mask"] = st.mask is not None
         out_info["prefix"] = st.prefix
         out_info["n_out"] = st.n
-        live_out = (jnp.int32(st.n) if st.mask is None
-                    else jnp.sum(st.mask, dtype=jnp.int32))
-        head = jnp.stack([live_out, overflow[0].astype(jnp.int32)])
+        with jax.named_scope(HEAD_SCOPE):
+            live_out = (jnp.int32(st.n) if st.mask is None
+                        else jnp.sum(st.mask, dtype=jnp.int32))
+            head = jnp.stack([live_out, overflow[0].astype(jnp.int32)])
         return tuple(st.cols), st.mask, head
 
-    return fn
+    return plan_dag
+
+
+_MODULE = re.compile(r"HloModule ([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLEE = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
+
+
+def _scope_of(op_name: str) -> Optional[str]:
+    return next((p for p in op_name.split("/") if p.startswith("plan.")),
+                None)
+
+
+def hlo_scopes(text: str) -> Tuple[Dict[str, str],
+                                   Dict[str, Tuple[str, ...]]]:
+    """({instruction: node scope}, {fusion: scopes of its computation})
+    from a compiled module's HLO text; see ``ProgramCache.op_scopes``.
+    An instruction with no ``plan.`` scope of its own takes that of the
+    root of the computation it calls (``calls=``, ``to_apply=``),
+    followed down until one has a scope."""
+    own: Dict[str, Optional[str]] = {}
+    callees: Dict[str, list] = {}
+    root: Dict[str, str] = {}           # computation -> ROOT instruction
+    members: Dict[str, list] = {}       # computation -> its instructions
+    comp = None
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                comp = c.group(1)
+                members[comp] = []
+            continue
+        name = m.group(2)
+        op = _OP_NAME.search(line)
+        own[name] = _scope_of(op.group(1)) if op else None
+        callees[name] = _CALLEE.findall(line)
+        if comp is not None:
+            members[comp].append(name)
+            if m.group(1):
+                root[comp] = name
+
+    def resolve(name: str, seen: frozenset) -> Optional[str]:
+        if own.get(name) is not None or name in seen:
+            return own.get(name)
+        for callee in callees.get(name, ()):
+            if callee in root:
+                s = resolve(root[callee], seen | {name})
+                if s is not None:
+                    return s
+        return None
+
+    def drawn(comp: str, seen: set) -> set:
+        out: set = set()
+        if comp in seen:
+            return out
+        seen.add(comp)
+        for n in members.get(comp, ()):
+            if own[n] is not None:
+                out.add(own[n])
+            for callee in callees[n]:
+                out |= drawn(callee, seen)
+        return out
+
+    scopes = {}
+    for name in own:
+        s = resolve(name, frozenset())
+        if s is not None:
+            scopes[name] = s
+    fused = {name: tuple(sorted(set().union(
+                 *(drawn(c, set()) for c in cs))))
+             for name, cs in callees.items() if cs}
+    return scopes, fused
 
 
 class ProgramCache:
@@ -538,12 +671,11 @@ class ProgramCache:
             plan_metrics.inc("plan_cache_hits")
             return prog
         plan_metrics.inc("plan_cache_misses")
-        t0 = time.perf_counter()
-        out_info: Dict[str, Any] = {}
-        fn = _make_fn(plan, max_groups, out_info, decisions)
-        jitted = jax.jit(fn, donate_argnums=(0,) if donate else ())
-        compiled = jitted.lower(tuple(table.columns)).compile()
-        plan_metrics.add_time("compile_s", time.perf_counter() - t0)
+        with timed_range("plan.compile", plan_metrics, "compile_s"):
+            out_info: Dict[str, Any] = {}
+            fn = _make_fn(plan, max_groups, out_info, decisions)
+            jitted = jax.jit(fn, donate_argnums=(0,) if donate else ())
+            compiled = jitted.lower(tuple(table.columns)).compile()
         plan_metrics.inc("plan_compiles")
         prog = CompiledPlan(compiled=compiled, fingerprint=key[0],
                             has_mask=out_info["has_mask"],
@@ -578,14 +710,13 @@ class ProgramCache:
             plan_metrics.inc("plan_cache_hits")
             return prog
         plan_metrics.inc("plan_cache_misses")
-        t0 = time.perf_counter()
-        out_info: Dict[str, Any] = {}
-        fn = _make_dag_fn(plan, decisions, max_groups, out_info)
-        jitted = jax.jit(fn)
-        compiled = jitted.lower(
-            tuple(tuple(t.columns) for t in tables),
-            tuple(aux)).compile()
-        plan_metrics.add_time("compile_s", time.perf_counter() - t0)
+        with timed_range("plan.compile", plan_metrics, "compile_s"):
+            out_info: Dict[str, Any] = {}
+            fn = _make_dag_fn(plan, decisions, max_groups, out_info)
+            jitted = jax.jit(fn)
+            compiled = jitted.lower(
+                tuple(tuple(t.columns) for t in tables),
+                tuple(aux)).compile()
         plan_metrics.inc("plan_compiles")
         prog = CompiledPlan(compiled=compiled, fingerprint=key[0],
                             has_mask=out_info["has_mask"],
@@ -615,11 +746,10 @@ class ProgramCache:
             return prog
         plan_metrics.inc("plan_cache_misses")
         from . import sharding  # lazy: sharding imports this module
-        t0 = time.perf_counter()
-        jitted, staged, in_specs, out_info, n = sharding.lower_sharded(
-            plan, table, mesh, max_groups)
-        compiled = jitted.lower(*staged).compile()
-        plan_metrics.add_time("compile_s", time.perf_counter() - t0)
+        with timed_range("plan.compile", plan_metrics, "compile_s"):
+            jitted, staged, in_specs, out_info, n = sharding.lower_sharded(
+                plan, table, mesh, max_groups)
+            compiled = jitted.lower(*staged).compile()
         plan_metrics.inc("plan_compiles")
         prog = CompiledShardedPlan(
             compiled=compiled, fingerprint=key[0],
@@ -658,12 +788,13 @@ class ProgramCache:
             plan_metrics.inc("plan_cache_hits")
             return prog
         plan_metrics.inc("plan_cache_misses")
-        t0 = time.perf_counter()
-        out_info: Dict[str, Any] = {}
-        fn = _make_fn(plan, max_groups, out_info)
-        jitted = jax.jit(jax.vmap(fn))
-        compiled = jitted.lower(stacked_cols).compile()
-        plan_metrics.add_time("compile_s", time.perf_counter() - t0)
+        with timed_range("plan.compile", plan_metrics, "compile_s"):
+            out_info: Dict[str, Any] = {}
+            batched = jax.vmap(_make_fn(plan, max_groups, out_info))
+
+            def plan_vmap(cols):
+                return batched(cols)
+            compiled = jax.jit(plan_vmap).lower(stacked_cols).compile()
         plan_metrics.inc("plan_compiles")
         prog = CompiledPlan(compiled=compiled, fingerprint=key[0],
                             has_mask=out_info["has_mask"],
@@ -672,6 +803,37 @@ class ProgramCache:
         with self._lock:
             prog = self._programs.setdefault(key, prog)
         return prog
+
+    def op_scopes(self) -> Dict[str, Dict[str, str]]:
+        """{program: {instruction name: node scope}} over the cached
+        programs, from each one's compiled HLO text (an executable loaded
+        from the persistent cache carries the same text). ``program`` is
+        the compiled module's name and the executable's fingerprint in
+        hex, ``jit_plan_fused(<fingerprint>)``: the module name is what a
+        profiler's ``XLA Modules`` event shares with it (the number there
+        is not this fingerprint). An instruction's scope is the first
+        ``plan.`` component of its ``op_name``; a fusion without one of
+        its own takes its root's. Instructions with neither are left
+        out. Parses on every call: never on the query path."""
+        return {k: hlo_scopes(t)[0] for k, t in self._texts().items()}
+
+    def fusion_scopes(self) -> Dict[str, Dict[str, Tuple[str, ...]]]:
+        """{program: {fusion name: the node scopes its fused computation
+        draws on}}: which nodes' operations XLA fused into one kernel."""
+        return {k: hlo_scopes(t)[1] for k, t in self._texts().items()}
+
+    def _texts(self) -> Dict[str, str]:
+        with self._lock:
+            progs = list(self._programs.values())
+        out = {}
+        for p in progs:
+            text = p.compiled.as_text()
+            m = _MODULE.match(text)
+            fp = p.compiled.runtime_executable().fingerprint
+            if isinstance(fp, bytes):
+                fp = fp.hex()
+            out[f"{m.group(1) if m else '?'}({fp})"] = text
+        return out
 
     def clear(self) -> None:
         with self._lock:

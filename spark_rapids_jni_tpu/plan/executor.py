@@ -45,6 +45,7 @@ from ..memory.exceptions import TpuSplitAndRetryOOM
 from ..memory.reservation import device_reservation, release_barrier
 from ..memory.retry import with_retry
 from ..utils import config
+from ..utils.tracing import timed_range
 from . import expr as ex
 from . import planner as _planner
 from . import split as _split
@@ -73,9 +74,10 @@ def _eager_fallback(plan: PlanNode, table, reason: str) -> Table:
     over immutable tables, so the guard's retry re-run is safe. SRJT021
     enforces the literal catalog reason at every call site of this
     forwarder, exactly as it does for a direct run_eager fallback."""
-    return guarded_dispatch(
-        "plan_execute",
-        lambda: run_eager(plan, table, fallback_reason=reason))  # srjt: noqa[SRJT021] — the forwarder itself; SRJT021 checks its callers' literals instead
+    with timed_range("plan.eager"):
+        return guarded_dispatch(
+            "plan_execute",
+            lambda: run_eager(plan, table, fallback_reason=reason))  # srjt: noqa[SRJT021] — the forwarder itself; SRJT021 checks its callers' literals instead
 
 
 def _pool_cap_check(want_bytes: int) -> None:
@@ -100,6 +102,22 @@ def _rollback_spill() -> None:
     freed = transport.rollback_all_stores()
     plan_metrics.inc("plan_oom_retries")
     plan_metrics.inc("plan_oom_spill_bytes", freed)
+
+
+def _released(out, took: bool):
+    """``release_barrier`` under ``plan.wait`` when it blocks (a
+    reservation was taken)."""
+    if not took:
+        return out
+    with timed_range("plan.wait"):
+        return release_barrier(out, took)
+
+
+def _head_sync(head) -> np.ndarray:
+    """THE host sync for the query: the head's live count and overflow
+    flag, under ``plan.wait``."""
+    with timed_range("plan.wait"):
+        return np.asarray(head)
 
 
 def _oom_budget() -> int:
@@ -278,6 +296,17 @@ def _resolve_dag_literals(plan: PlanNode, tables: Tuple[Table, ...]
     return new_plan
 
 
+def _trim(prog, cols, mask, live: int) -> Table:
+    """The program's padded output cut to its ``live`` rows on the host
+    side: as is, a prefix slice, or a compaction of the masked rows."""
+    if mask is None:
+        return Table(tuple(cols))
+    if prog.prefix:
+        return _trim_prefix(cols, live)
+    idx = mask_indices_core(mask, live)
+    return gather_table(Table(tuple(cols)), idx)
+
+
 def _execute_dag(plan: PlanNode, tables: Tuple[Table, ...],
                  cache: ProgramCache) -> Table:
     """DAG (Join-bearing / multi-input) execution: planner passes, one
@@ -288,35 +317,36 @@ def _execute_dag(plan: PlanNode, tables: Tuple[Table, ...],
     if len(tables) < k:
         raise PlanError(f"plan reads {k} inputs, got {len(tables)}")
     tables = tuple(tables[:k])
-    plan = _resolve_dag_literals(plan, tables)
-    for t in tables:
-        if _table_unsupported_reason(t) is not None:
-            return _eager_fallback(plan, tables, "unsupported-input")
-        if any(c.dtype.id in (dt.TypeId.RLE, dt.TypeId.FOR32,
-                              dt.TypeId.FOR64) for c in t.columns):
-            # join lowering reads key lanes straight from column data
-            # (_key_values) — run/packed layouts need a decode the DAG
-            # fuser doesn't model yet; the eager interpreter decodes at
-            # its join boundary instead
-            return _eager_fallback(plan, tables, "unsupported-input")
+    with timed_range("plan.prepare", plan_metrics, "prepare_s"):
+        plan = _resolve_dag_literals(plan, tables)
+        for t in tables:
+            if _table_unsupported_reason(t) is not None:
+                return _eager_fallback(plan, tables, "unsupported-input")
+            if any(c.dtype.id in (dt.TypeId.RLE, dt.TypeId.FOR32,
+                                  dt.TypeId.FOR64) for c in t.columns):
+                # join lowering reads key lanes straight from column data
+                # (_key_values) — run/packed layouts need a decode the
+                # DAG fuser doesn't model yet; the eager interpreter
+                # decodes at its join boundary instead
+                return _eager_fallback(plan, tables, "unsupported-input")
 
-    opt = _planner.optimize(plan, tables)
-    decisions = _planner.plan_decisions(opt, tables)
-    if decisions.eager_reason is not None:
-        return _eager_fallback(plan, tables, "planner-unsupported")
+        opt = _planner.optimize(plan, tables)
+        decisions = _planner.plan_decisions(opt, tables)
+        if decisions.eager_reason is not None:
+            return _eager_fallback(plan, tables, "planner-unsupported")
 
-    aux: List[jnp.ndarray] = []
-    for jid, (lsrc, rsrc) in decisions.dict_joins.items():
-        from ..columnar.dictionary import code_remap_table, dict_values
-        lcol = tables[lsrc[0]].columns[lsrc[1]]
-        rcol = tables[rsrc[0]].columns[rsrc[1]]
-        remap = code_remap_table(lcol, rcol)
-        if remap is None:  # co-dictionary after all: identity remap
-            remap = np.arange(dict_values(rcol).size, dtype=np.int32)
-        aux.append(jnp.asarray(remap))
+        aux: List[jnp.ndarray] = []
+        for jid, (lsrc, rsrc) in decisions.dict_joins.items():
+            from ..columnar.dictionary import code_remap_table, dict_values
+            lcol = tables[lsrc[0]].columns[lsrc[1]]
+            rcol = tables[rsrc[0]].columns[rsrc[1]]
+            remap = code_remap_table(lcol, rcol)
+            if remap is None:  # co-dictionary after all: identity remap
+                remap = np.arange(dict_values(rcol).size, dtype=np.int32)
+            aux.append(jnp.asarray(remap))
 
-    prog: CompiledPlan = cache.get_or_compile_dag(opt, tables, decisions,
-                                                  tuple(aux))
+        prog: CompiledPlan = cache.get_or_compile_dag(
+            opt, tables, decisions, tuple(aux))
 
     nbytes = sum(t.device_nbytes() for t in tables)
 
@@ -325,23 +355,20 @@ def _execute_dag(plan: PlanNode, tables: Tuple[Table, ...],
         with device_reservation(2 * nbytes) as took:
             out = prog.compiled(tuple(tuple(t.columns) for t in tables),
                                 tuple(aux))
-            return release_barrier(out, took)
+            return _released(out, took)
 
     def attempt(_arg):
         t0 = time.perf_counter()
-        cols, mask, head = guarded_dispatch("plan_execute", run)
-        head_h = np.asarray(head)       # THE host sync for the query
+        with timed_range("plan.dispatch", plan_metrics, "dispatch_s"):
+            cols, mask, head = guarded_dispatch("plan_execute", run)
+        head_h = _head_sync(head)
         plan_metrics.add_time("execute_s", time.perf_counter() - t0)
         plan_metrics.inc("plan_executes")
         live, overflow = int(head_h[0]), bool(head_h[1])
         if overflow:
             raise _OverflowSignal()
-        if mask is None:
-            return Table(tuple(cols))
-        if prog.prefix:
-            return _trim_prefix(cols, live)
-        idx = mask_indices_core(mask, live)
-        return gather_table(Table(tuple(cols)), idx)
+        with timed_range("plan.trim", plan_metrics, "trim_s"):
+            return _trim(prog, cols, mask, live)
 
     try:
         # retry/rollback re-dispatch the SAME compiled DAG program; a
@@ -376,22 +403,22 @@ def execute_plan(plan: PlanNode,
     if is_dag(plan) or not isinstance(table, Table):
         tables = (table,) if isinstance(table, Table) else tuple(table)
         return _execute_dag(plan, tables, cache)
-    plan = resolve_dict_literals(plan, table)
-    if donate_input and any(
-            c.dtype.id in (dt.TypeId.DICT32, dt.TypeId.RLE,
-                           dt.TypeId.FOR32, dt.TypeId.FOR64)
-            for c in table.columns):
-        # encoded children (dictionary values/ranks, RLE run buffers, FOR
-        # reference headers) are SHARED by reference across batches —
-        # donating them would let XLA scribble over buffers other queries
-        # still reference
-        donate_input = False
-    reason = unsupported_reason(plan, table)
-    if reason is not None:
-        return _eager_fallback(plan, table, "unsupported-input")
-
-    prog: CompiledPlan = cache.get_or_compile(plan, table,
-                                              donate=donate_input)
+    with timed_range("plan.prepare", plan_metrics, "prepare_s"):
+        plan = resolve_dict_literals(plan, table)
+        if donate_input and any(
+                c.dtype.id in (dt.TypeId.DICT32, dt.TypeId.RLE,
+                               dt.TypeId.FOR32, dt.TypeId.FOR64)
+                for c in table.columns):
+            # encoded children (dictionary values/ranks, RLE run buffers,
+            # FOR reference headers) are SHARED by reference across
+            # batches — donating them would let XLA scribble over buffers
+            # other queries still reference
+            donate_input = False
+        reason = unsupported_reason(plan, table)
+        if reason is not None:
+            return _eager_fallback(plan, table, "unsupported-input")
+        prog: CompiledPlan = cache.get_or_compile(plan, table,
+                                                  donate=donate_input)
 
     def _fused_once(pr: CompiledPlan, t: Table) -> Table:
         def run():
@@ -400,22 +427,19 @@ def execute_plan(plan: PlanNode,
             _pool_cap_check(2 * t.device_nbytes())
             with device_reservation(2 * t.device_nbytes()) as took:
                 out = pr.compiled(tuple(t.columns))
-                return release_barrier(out, took)
+                return _released(out, took)
 
         t0 = time.perf_counter()
-        cols, mask, head = guarded_dispatch("plan_execute", run)
-        head_h = np.asarray(head)       # THE host sync for the query
+        with timed_range("plan.dispatch", plan_metrics, "dispatch_s"):
+            cols, mask, head = guarded_dispatch("plan_execute", run)
+        head_h = _head_sync(head)
         plan_metrics.add_time("execute_s", time.perf_counter() - t0)
         plan_metrics.inc("plan_executes")
         live, overflow = int(head_h[0]), bool(head_h[1])
         if overflow:
             raise _OverflowSignal()
-        if mask is None:
-            return Table(tuple(cols))
-        if pr.prefix:
-            return _trim_prefix(cols, live)
-        idx = mask_indices_core(mask, live)
-        return gather_table(Table(tuple(cols)), idx)
+        with timed_range("plan.trim", plan_metrics, "trim_s"):
+            return _trim(pr, cols, mask, live)
 
     if donate_input:
         # donation consumes the input mid-program: a rollback or split

@@ -25,40 +25,41 @@ from __future__ import annotations
 import time
 from typing import Optional
 
-import numpy as np
-
 from ..columnar.column import Table
 from ..faultinj import guard
 from ..faultinj.guard import (FaultStormError, ProgramPoisonedError,
                               guarded_dispatch)
-from ..memory.reservation import device_reservation, release_barrier
+from ..memory.reservation import device_reservation
 from ..parallel import cluster
+from ..utils.tracing import timed_range
 from . import sharding
 from .compile import CompiledShardedPlan, ProgramCache, plan_metrics
-from .executor import (_default_cache, _trim_prefix, execute_plan,
+from .executor import (_default_cache, _eager_fallback, _head_sync,
+                       _released, _trim, execute_plan,
                        resolve_dict_literals, unsupported_reason)
-from .interpreter import run_eager
 from .nodes import PlanNode, is_dag
 
 
-def _execute_on_mesh(plan: PlanNode, table: Table, mesh,
-                     cache: ProgramCache) -> Table:
-    prog: CompiledShardedPlan = cache.get_or_compile_sharded(
-        plan, table, mesh)
+def _execute_on_mesh(plan: PlanNode, table: Table,
+                     prog: CompiledShardedPlan) -> Table:
+    mesh = prog.mesh
 
     def run():
         # stage inside the guard: device_put re-commits leaves to their
         # shardings (free when already conformant, and a degraded replay
         # restages onto the smaller mesh from the same host/solo buffers)
-        leaves, specs, _meta, _n, _per = sharding.table_layout(table, mesh)
-        staged = sharding.stage_leaves(leaves, specs, mesh)
+        with timed_range("plan.stage", plan_metrics, "stage_s"):
+            leaves, specs, _meta, _n, _per = sharding.table_layout(table,
+                                                                   mesh)
+            staged = sharding.stage_leaves(leaves, specs, mesh)
         with device_reservation(2 * table.device_nbytes()) as took:
             out = prog.compiled(*staged)
-            return release_barrier(out, took)
+            return _released(out, took)
 
     t0 = time.perf_counter()
-    out_leaves, mask, head = guarded_dispatch("plan_execute", run)
-    head_h = np.asarray(head)           # THE host sync for the query
+    with timed_range("plan.dispatch", plan_metrics, "dispatch_s"):
+        out_leaves, mask, head = guarded_dispatch("plan_execute", run)
+    head_h = _head_sync(head)
     plan_metrics.add_time("execute_s", time.perf_counter() - t0)
     plan_metrics.inc("plan_executes")
     live, overflow = int(head_h[0]), bool(head_h[1])
@@ -67,13 +68,10 @@ def _execute_on_mesh(plan: PlanNode, table: Table, mesh,
         plan_metrics.inc("plan_overflows")
         return _eager_fallback(plan, table, "overflow")
 
-    cols = sharding.rebuild_outputs(prog.replicated, prog.out_cols,
-                                    out_leaves, table)
-    if prog.prefix:
-        return _trim_prefix(cols, live)
-    from ..columnar.table_ops import gather_table, mask_indices_core
-    idx = mask_indices_core(mask, live)
-    return gather_table(Table(tuple(cols)), idx)
+    with timed_range("plan.trim", plan_metrics, "trim_s"):
+        cols = sharding.rebuild_outputs(prog.replicated, prog.out_cols,
+                                        out_leaves, table)
+        return _trim(prog, cols, mask, live)
 
 
 def execute_plan_sharded(plan: PlanNode, table: Table,
@@ -87,22 +85,25 @@ def execute_plan_sharded(plan: PlanNode, table: Table,
         # DAG plans are gated solo by sharding_unsupported_reason; route
         # straight to the (DAG-aware) solo executor without linearizing
         return execute_plan(plan, table, cache=cache)
-    plan = resolve_dict_literals(plan, table)
-    reason = unsupported_reason(plan, table)
-    if reason is not None:
-        return _eager_fallback(plan, table, "unsupported-input")
     if mesh is None:
         mesh = cluster.get_mesh(devices)
     if (int(mesh.devices.size) == 1
             or sharding.sharding_unsupported_reason(plan, table)
             is not None):
-        # same bits either way — run the solo fused program
+        # same bits either way — run the solo fused program, whose own
+        # plan.prepare is then the query's only one
         return execute_plan(plan, table, cache=cache)
+    with timed_range("plan.prepare", plan_metrics, "prepare_s"):
+        plan = resolve_dict_literals(plan, table)
+        reason = unsupported_reason(plan, table)
+        if reason is not None:
+            return _eager_fallback(plan, table, "unsupported-input")
+        prog = cache.get_or_compile_sharded(plan, table, mesh)
 
     axis = sharding.mesh_axis(mesh)
     while True:
         try:
-            return _execute_on_mesh(plan, table, mesh, cache)
+            return _execute_on_mesh(plan, table, prog)
         except (FaultStormError, ProgramPoisonedError):
             nd = int(mesh.devices.size) // 2
             if nd < 1:
@@ -114,3 +115,6 @@ def execute_plan_sharded(plan: PlanNode, table: Table,
                 with guard.degraded():
                     return execute_plan(plan, table, cache=cache)
             mesh = cluster.get_mesh(nd, axis)
+            # a replay's compile counts in compile_s; prepare_s stays one
+            # addition per query
+            prog = cache.get_or_compile_sharded(plan, table, mesh)

@@ -56,6 +56,7 @@ from ..ops.sort import gather, sort_lanes, stable_lexsort
 from ..parallel import cluster
 from ..utils.shapes import bucket_size
 from . import expr as ex
+from .compile import HEAD_SCOPE, node_scope
 from .nodes import (Filter, GroupBy, Limit, PlanError, PlanNode, Project,
                     Sort, is_dag, linearize)
 
@@ -372,114 +373,123 @@ def make_sharded_fn(plan: PlanNode, max_groups: int, mesh,
     axis = mesh_axis(mesh)
     nd = int(mesh.devices.size)
 
-    def body(*leaves):
-        it = iter(leaves)
-        cols = [_rebuild_col(m, it, per) for m in meta]
+    def plan_sharded(*leaves):
+        with jax.named_scope(node_scope(0, nodes[0])):
+            it = iter(leaves)
+            cols = [_rebuild_col(m, it, per) for m in meta]
+            gid = (lax.axis_index(axis).astype(jnp.int32) * per
+                   + jnp.arange(per, dtype=jnp.int32))
+            live_local = gid < n                 # pad-row liveness
         # DICT32 passthrough tracking: a Project of col(i) keeps the
         # input's children tuple by reference, so identity recovers which
         # dictionary to reattach on the host side
         child_src = {id(c.children): i for i, c in enumerate(cols)
                      if c.children}
-        gid = (lax.axis_index(axis).astype(jnp.int32) * per
-               + jnp.arange(per, dtype=jnp.int32))
-        live_local = gid < n                 # pad-row liveness
         mask = None
         live = None
         replicated = False
         prefix = True
         overflow = jnp.asarray(False)
         ncur = per
-        for node in nodes[1:]:
-            if isinstance(node, Filter):
-                keep = ex.predicate_mask(ex.eval_expr(node.predicate, cols))
-                mask = keep if mask is None else mask & keep
-                if replicated:
-                    live = jnp.sum(mask, dtype=jnp.int32)
-                prefix = False
-            elif isinstance(node, Project):
-                cols = [ex.project_column(e, cols, ncur)
-                        for e in node.exprs]
-            elif isinstance(node, GroupBy):
-                if not replicated:
-                    row_mask = (live_local if mask is None
-                                else (mask & live_local))
-                    cols, live, ov = _sharded_groupby(
-                        node, cols, row_mask, axis, nd, per, n, max_groups)
-                    overflow = overflow | ov
-                    replicated = True
-                    ncur = bucket_size(min(max_groups, n))
-                else:
-                    G = bucket_size(min(max_groups, ncur))
+        for pos, node in enumerate(nodes[1:], 1):
+            with jax.named_scope(node_scope(pos, node)):
+                if isinstance(node, Filter):
+                    keep = ex.predicate_mask(
+                        ex.eval_expr(node.predicate, cols))
+                    mask = keep if mask is None else mask & keep
+                    if replicated:
+                        live = jnp.sum(mask, dtype=jnp.int32)
+                    prefix = False
+                elif isinstance(node, Project):
+                    cols = [ex.project_column(e, cols, ncur)
+                            for e in node.exprs]
+                elif isinstance(node, GroupBy):
+                    if not replicated:
+                        row_mask = (live_local if mask is None
+                                    else (mask & live_local))
+                        cols, live, ov = _sharded_groupby(
+                            node, cols, row_mask, axis, nd, per, n,
+                            max_groups)
+                        overflow = overflow | ov
+                        replicated = True
+                        ncur = bucket_size(min(max_groups, n))
+                    else:
+                        G = bucket_size(min(max_groups, ncur))
+                        keys = [cols[i] for i in node.keys]
+                        aggs = [(cols[i], op) for i, op in node.aggs]
+                        cols, live, ov = groupby_core(keys, aggs, mask, G)
+                        overflow = overflow | ov
+                        ncur = G
+                    mask = jnp.arange(ncur, dtype=jnp.int32) < live
+                    prefix = True
+                elif isinstance(node, Sort):
+                    if not replicated:
+                        raise PlanError(
+                            "sharded Sort before GroupBy (gate this plan "
+                            "via sharding_unsupported_reason)")
                     keys = [cols[i] for i in node.keys]
-                    aggs = [(cols[i], op) for i, op in node.aggs]
-                    cols, live, ov = groupby_core(keys, aggs, mask, G)
-                    overflow = overflow | ov
-                    ncur = G
-                mask = jnp.arange(ncur, dtype=jnp.int32) < live
-                prefix = True
-            elif isinstance(node, Sort):
-                if not replicated:
+                    lanes = sort_lanes(keys, node.ascending,
+                                       node.nulls_first)
+                    if mask is not None:
+                        # dead lane LAST == most significant: live first
+                        lanes.append((~mask).astype(jnp.uint8))
+                    order = stable_lexsort(lanes)
+                    cols = [gather(c, order) for c in cols]
+                    if mask is not None:
+                        mask = jnp.take(mask, order)
+                    prefix = True
+                elif isinstance(node, Limit):
+                    if not replicated:
+                        raise PlanError(
+                            "sharded Limit before GroupBy (gate this plan "
+                            "via sharding_unsupported_reason)")
+                    k = min(node.count, ncur)
+                    cols = [_slice_col(c, k) for c in cols]
+                    if mask is not None:
+                        mask = mask[:k]
+                        live = jnp.minimum(live, jnp.int32(k))
+                    ncur = k
+                else:
                     raise PlanError(
-                        "sharded Sort before GroupBy (gate this plan via "
-                        "sharding_unsupported_reason)")
-                keys = [cols[i] for i in node.keys]
-                lanes = sort_lanes(keys, node.ascending, node.nulls_first)
-                if mask is not None:
-                    # dead lane LAST == most significant: live rows first
-                    lanes.append((~mask).astype(jnp.uint8))
-                order = stable_lexsort(lanes)
-                cols = [gather(c, order) for c in cols]
-                if mask is not None:
-                    mask = jnp.take(mask, order)
-                prefix = True
-            elif isinstance(node, Limit):
-                if not replicated:
-                    raise PlanError(
-                        "sharded Limit before GroupBy (gate this plan via "
-                        "sharding_unsupported_reason)")
-                k = min(node.count, ncur)
-                cols = [_slice_col(c, k) for c in cols]
-                if mask is not None:
-                    mask = mask[:k]
-                    live = jnp.minimum(live, jnp.int32(k))
-                ncur = k
-            else:
-                raise PlanError(f"unknown plan node {type(node).__name__}")
+                        f"unknown plan node {type(node).__name__}")
 
         out_leaves: List[Any] = []
         out_cols_meta: List[Dict] = []
-        if replicated:
-            for c in cols:
-                out_cols_meta.append(
-                    _flatten_col(c, False, mesh, out_leaves, None))
-            mask_out = mask      # never None after a GroupBy
-            live_out = live.astype(jnp.int32)
-            out_info["prefix"] = prefix
-            out_info["n_out"] = ncur
-        else:
-            # row-sharded outputs: data/validity only; children reattach
-            # from the input table on the host side
-            for c in cols:
-                out_cols_meta.append({
-                    "dtype": c.dtype,
-                    "validity": c.validity is not None,
-                    "children_from": (child_src.get(id(c.children))
-                                      if c.children else None),
-                })
-                out_leaves.append(c.data)
-                if c.validity is not None:
-                    out_leaves.append(c.validity)
-            mask_out = live_local if mask is None else (mask & live_local)
-            live_out = lax.psum(jnp.sum(mask_out, dtype=jnp.int32), axis)
-            out_info["prefix"] = mask is None    # pads trail unfiltered
-            out_info["n_out"] = per * nd
-        out_info["replicated"] = replicated
-        out_info["has_mask"] = True
-        out_info["out_cols"] = out_cols_meta
-        head = jnp.stack([live_out, overflow.astype(jnp.int32)])
+        with jax.named_scope(HEAD_SCOPE):
+            if replicated:
+                for c in cols:
+                    out_cols_meta.append(
+                        _flatten_col(c, False, mesh, out_leaves, None))
+                mask_out = mask      # never None after a GroupBy
+                live_out = live.astype(jnp.int32)
+                out_info["prefix"] = prefix
+                out_info["n_out"] = ncur
+            else:
+                # row-sharded outputs: data/validity only; children
+                # reattach from the input table on the host side
+                for c in cols:
+                    out_cols_meta.append({
+                        "dtype": c.dtype,
+                        "validity": c.validity is not None,
+                        "children_from": (child_src.get(id(c.children))
+                                          if c.children else None),
+                    })
+                    out_leaves.append(c.data)
+                    if c.validity is not None:
+                        out_leaves.append(c.validity)
+                mask_out = (live_local if mask is None
+                            else (mask & live_local))
+                live_out = lax.psum(jnp.sum(mask_out, dtype=jnp.int32),
+                                    axis)
+                out_info["prefix"] = mask is None  # pads trail unfiltered
+                out_info["n_out"] = per * nd
+            out_info["replicated"] = replicated
+            out_info["has_mask"] = True
+            out_info["out_cols"] = out_cols_meta
+            head = jnp.stack([live_out, overflow.astype(jnp.int32)])
         return tuple(out_leaves), mask_out, head
 
-    return body
+    return plan_sharded
 
 
 def lower_sharded(plan: PlanNode, table: Table, mesh, max_groups: int):
