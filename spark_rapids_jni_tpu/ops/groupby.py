@@ -7,8 +7,11 @@ The reference gets groupby from vendored libcudf. TPU-first design:
      Spark semantics).
   2. Segment boundaries = sorted keys differ from their predecessor
      (vectorized compare, no hashing collisions to resolve).
-  3. ``jax.ops.segment_*`` reductions over the sorted value columns
-     (num_segments read back once — the only host sync).
+  3. Segment reductions over the sorted value columns (num_segments
+     read back once — the only host sync). Integer sums and counts come
+     from block totals (``_block_segment_sums``): an int64 scatter-add
+     costs a TPU v5e about 85 ns a row, the rows of one slot applied one
+     after another. Float sums and min/max keep ``jax.ops.segment_*``.
 
 Aggregations: sum / count / min / max / mean with Spark null semantics
 (nulls ignored; all-null group → null result; count counts non-nulls).
@@ -80,6 +83,132 @@ def _segment_structure(cmp_keys, order):
     return boundary, seg_ids
 
 
+# rows per block of ``_block_segment_sums``: the TPU's lane width. At
+# 4096 slots and 6M rows a sum's block totals take 375 KB, and the
+# blocks its slots end in 4 MB.
+_SUM_BLOCK = 128
+
+
+def _block_rows(n: int, num_segments: int) -> int:
+    """``_SUM_BLOCK``, or 1 (a plain prefix over the rows) where the
+    slots' end blocks would outgrow both the rows and 2^20 values: a
+    groupby with about as many groups as rows. Narrower blocks than the
+    lane width take the TPU several times the code of either (no-chip
+    v5e compile: 10.7 MB for 16 sums in blocks of 4, 1.2 MB in blocks of
+    128, 1.9 MB in blocks of 1)."""
+    fits = _SUM_BLOCK * num_segments <= max(n, 1 << 20)
+    return _SUM_BLOCK if fits else 1
+
+
+def _count_sums(path: str, k: int = 1) -> None:
+    """``k`` segment sums built through ``path`` (block or scatter): once
+    per compile inside a fused program, once per call in the eager op."""
+    from ..plan.compile import plan_metrics  # plan.compile imports us
+    plan_metrics.inc(f"plan_{path}_sums", k)
+
+
+def _segment_ends(first: jnp.ndarray, total, n: int) -> jnp.ndarray:
+    """i32[G]: the last sorted row of each of G slots, from ``first``
+    (``nonzero_indices`` of the segment boundaries: the first row of
+    each of the first G segments) and ``total``, the number of
+    segments. Slot g ends where segment g + 1 starts, and a slot past
+    the last segment where the rows do, so its sum is 0; with more
+    segments than slots, the last slot ends at the last row."""
+    nxt = jnp.concatenate([first[1:].astype(jnp.int32),
+                           jnp.full((1,), n, jnp.int32)])
+    slot = jnp.arange(1, first.shape[0] + 1, dtype=jnp.int32)
+    return jnp.where(slot < total, nxt, n) - 1
+
+
+def _block_segment_sums(zs: Sequence[jnp.ndarray],
+                        ends: jnp.ndarray) -> jnp.ndarray:
+    """i64[k, G]: per-slot sums of each integer ``zs[i]`` over the same
+    contiguous sorted segments, with no scatter. ``ends``
+    (``_segment_ends``) holds each slot's last row, nondecreasing. The
+    inclusive prefix at a slot's end is the exclusive prefix of its
+    block's total plus the block's lanes up to the end; a slot's sum is
+    the difference of its and its predecessor's prefix. int64 addition
+    wraps, so the difference is exact mod 2^64: bit-identical to
+    ``jax.ops.segment_sum``. Padding slots read 0."""
+    _count_sums("block", len(zs))
+    return _block_sums(tuple(zs), ends)
+
+
+@jax.jit
+def _block_sums(zs: Tuple[jnp.ndarray, ...], ends: jnp.ndarray):
+    # the k sums run end to end, one set of kernels for all: on the TPU
+    # each kernel of a fused program holds device memory for its code,
+    # about 1 MB for one sum's block kernels against 0.2 MB for a
+    # scatter-add (no-chip v5e compile at 6M rows). Sum i's prefix at
+    # its row e is the prefix of the whole at i*n + e less the prefix
+    # just before i*n, wherever the blocks fall.
+    k, n, num_segments = len(zs), zs[0].shape[0], ends.shape[0]
+    rows = _block_rows(n, num_segments)
+    flat = jnp.concatenate([z.astype(jnp.int64) for z in zs])
+    blocks = jnp.pad(flat, (0, (-k * n) % (8 * rows))).reshape(-1, rows)
+    totals = jnp.sum(blocks, axis=1)
+    before = prefix_sum(totals) - totals
+    pos_t = jnp.int32 if blocks.size < 2**31 else jnp.int64
+
+    def prefix_at(pos):  # inclusive prefix of the whole at pos >= 0
+        blk, lane = pos // rows, pos % rows
+        upto = jnp.arange(rows, dtype=pos_t)[None, :] <= lane[:, None]
+        return jnp.take(before, blk) + jnp.sum(jnp.where(
+            upto, jnp.take(blocks, blk, axis=0), jnp.int64(0)), axis=1)
+
+    start = jnp.arange(k, dtype=pos_t) * n
+    at = (start[:, None] + ends.astype(pos_t)[None, :]).reshape(-1)
+    # the prefix before sum i is the whole's at i*n - 1; none before sum 0
+    got = prefix_at(jnp.concatenate([at, jnp.maximum(start - 1, 0)]))
+    lead = jnp.where(start > 0, got[k * num_segments:], jnp.int64(0))
+    incl = got[:k * num_segments].reshape(k, num_segments)
+    return incl - jnp.concatenate([lead[:, None], incl[:, :-1]], axis=1)
+
+
+def _sorted_counts_and_sums(aggs, order, valids, ends):
+    """Per aggregate ``(count, sum)`` over sorted segments, from block
+    sums: the count of ``valids[i]`` (taken once per distinct validity)
+    and, for an integer sum or mean, the sum of its values (once per
+    value column); ``sum`` is None for every other aggregate. ``aggs``:
+    (value Column, op); ``valids``: each one's per-sorted-row
+    contribution mask."""
+    if not aggs:
+        return []
+    zs: list = []
+    at: dict = {}
+
+    def row(key, make):
+        if key not in at:
+            at[key] = len(zs)
+            zs.append(make())
+        return at[key]
+
+    picks = []
+    for (vcol, op), valid in zip(aggs, valids):
+        _agg_out_dtype(vcol.dtype, op)  # validates op/type pair
+        vkey = None if vcol.validity is None else id(vcol.validity)
+        c = row(("count", vkey), lambda: valid)
+        s = None
+        # integer values only (an int64 sum): a prefix difference would
+        # change float rounding, and decimal limbs carry their own sums
+        if op in ("sum", "mean") and \
+                _agg_out_dtype(vcol.dtype, "sum").id is dt.TypeId.INT64:
+            s = row(("sum", id(vcol)), lambda: jnp.where(
+                valid, jnp.take(_agg_values(vcol)[0], order), jnp.int64(0)))
+        picks.append((c, s))
+    out = _block_segment_sums(zs, ends)
+    return [(out[c], None if s is None else out[s]) for c, s in picks]
+
+
+def _scatter_segment_sum(z: jnp.ndarray, seg: jnp.ndarray,
+                         num_segments: int,
+                         sorted_ids: bool = False) -> jnp.ndarray:
+    """``jax.ops.segment_sum``, counted: unsorted ids and float sums."""
+    _count_sums("scatter")
+    return jax.ops.segment_sum(z, seg, num_segments=num_segments,
+                               indices_are_sorted=sorted_ids)
+
+
 def _decimal128_segment_sum(vcol: Column, order, valid, seg_ids,
                             num_segments: int, any_valid,
                             with_overflow: bool = False):
@@ -97,13 +226,12 @@ def _decimal128_segment_sum(vcol: Column, order, valid, seg_ids,
     4 equals the sign extension of limb 3's top bit)."""
     limbs = jnp.take(vcol.data, order, axis=0)          # u32[n, 4] sorted
     limbs = jnp.where(valid[:, None], limbs, jnp.uint32(0))
-    s = jax.ops.segment_sum(limbs.astype(jnp.int64), seg_ids,
-                            num_segments=num_segments,
-                            indices_are_sorted=True)    # i64[g, 4]
+    s = _scatter_segment_sum(limbs.astype(jnp.int64), seg_ids,
+                             num_segments, True)        # i64[g, 4]
     neg = (limbs[:, 3] >> np.uint32(31)) != 0           # invalid rows are 0
-    s4 = jax.ops.segment_sum(
+    s4 = _scatter_segment_sum(
         jnp.where(neg, np.int64(0xFFFFFFFF), np.int64(0)), seg_ids,
-        num_segments=num_segments, indices_are_sorted=True)
+        num_segments, True)
     out = []
     carry = jnp.zeros((num_segments,), dtype=jnp.int64)
     for j in range(4):
@@ -183,24 +311,27 @@ def _decimal128_segment_mean(vcol: Column, order, valid, seg_ids,
 
 def _segment_agg_fixed(vcol: Column, order, valid, seg_ids,
                        num_segments: int, cnt, op: str,
-                       sorted_ids: bool = True) -> Column:
+                       sorted_ids: bool = True, s=None) -> Column:
     """One non-decimal aggregation over sorted segments — the pure jnp
     body shared by the eager op and the fused plan core. ``valid`` is the
     per-sorted-row contribution mask (null mask, optionally ANDed with a
     pushed-down row mask by the fused core); masked rows contribute the
-    op's identity, so the caller's ``cnt`` (segment_sum of ``valid``)
-    already carries the null/mask semantics."""
+    op's identity, so the caller's ``cnt`` (segment sum of ``valid``)
+    already carries the null/mask semantics. ``s``: a sum or mean's
+    segment sum where the caller took it (``_sorted_counts_and_sums``);
+    None takes it here by scatter-add."""
     out_dtype = _agg_out_dtype(vcol.dtype, op)  # validates op/type pair
     if op == "count":
         return Column(dt.INT64, num_segments, data=cnt)
-    vals, is_float = _agg_values(vcol)
-    if order is not None:  # None: rows already in segment-id order space
-        vals = jnp.take(vals, order)
+    if s is None:  # no sum from the caller: this op reads the values
+        vals, is_float = _agg_values(vcol)
+        if order is not None:  # None: rows already in segment-id order
+            vals = jnp.take(vals, order)
     any_valid = cnt > 0
     if op in ("sum", "mean"):
-        z = jnp.where(valid, vals, jnp.zeros_like(vals))
-        s = jax.ops.segment_sum(z, seg_ids, num_segments=num_segments,
-                                indices_are_sorted=sorted_ids)
+        if s is None:
+            z = jnp.where(valid, vals, jnp.zeros_like(vals))
+            s = _scatter_segment_sum(z, seg_ids, num_segments, sorted_ids)
         if op == "mean":
             m = s / jnp.maximum(cnt, 1).astype(s.dtype)
             return Column(dt.FLOAT64, num_segments,
@@ -344,8 +475,8 @@ def _dict_code_groupby(table: Table, key_indices, aggs, row_mask):
                 f"({n},)")  # mirror filter_table's contract
     else:
         live = jnp.ones((n,), bool)
-    rows_in_slot = jax.ops.segment_sum(live.astype(jnp.int32), slot,
-                                       num_segments=card + 1)
+    rows_in_slot = _scatter_segment_sum(live.astype(jnp.int32), slot,
+                                        card + 1)
     present = rows_in_slot > 0
     pos = jnp.cumsum(present.astype(jnp.int32)) - 1  # slot -> group id
     true_segments = int(jnp.sum(present))  # the op's one host sync
@@ -375,9 +506,9 @@ def _dict_code_groupby(table: Table, key_indices, aggs, row_mask):
         if ci not in cnt_cache:
             v = vcol.valid_mask() & live
             # accumulate in i32 (n < 2^31) — scatter-add is the hot loop
-            cnt_cache[ci] = (v, jax.ops.segment_sum(
+            cnt_cache[ci] = (v, _scatter_segment_sum(
                 v.astype(jnp.int32), seg_ids,
-                num_segments=num_segments).astype(jnp.int64))
+                num_segments).astype(jnp.int64))
         v, cnt = cnt_cache[ci]
         if op == "count":
             out_cols.append(Column(dt.INT64, num_segments, data=cnt))
@@ -437,8 +568,8 @@ def _rle_groupby(table: Table, key_indices, aggs, row_mask):
             raise ValueError(
                 f"boolean row_mask shape {live.shape} != table rows "
                 f"({n},)")  # mirror filter_table's contract
-        rows_in_slot = jax.ops.segment_sum(live.astype(jnp.int32), slot,
-                                           num_segments=num_groups)
+        rows_in_slot = _scatter_segment_sum(live.astype(jnp.int32), slot,
+                                            num_groups)
         present = rows_in_slot > 0
         pos = prefix_sum(present.astype(jnp.int32)) - 1
         true_segments = int(jnp.sum(present))  # the op's one host sync
@@ -469,9 +600,9 @@ def _rle_groupby(table: Table, key_indices, aggs, row_mask):
         _agg_out_dtype(vcol.dtype, op)  # validates op/type pair
         if ci not in cnt_cache:
             v = vcol.valid_mask() & live
-            cnt_cache[ci] = (v, jax.ops.segment_sum(
+            cnt_cache[ci] = (v, _scatter_segment_sum(
                 v.astype(jnp.int32), seg_ids,
-                num_segments=num_segments).astype(jnp.int64))
+                num_segments).astype(jnp.int64))
         v, cnt = cnt_cache[ci]
         if op == "count":
             out_cols.append(Column(dt.INT64, num_segments, data=cnt))
@@ -557,16 +688,15 @@ def _groupby_aggregate(
     # already synced, so the boundary→index expansion stays on device
     first_in_seg = nonzero_indices(boundary, num_segments)
     rep_rows = jnp.take(order, first_in_seg)
-
     out_cols = [gather(k, rep_rows) for k in keys]
 
-    for ci, op in aggs:
-        vcol = table.columns[ci]
-        out_dtype = _agg_out_dtype(vcol.dtype, op)  # validates op/type pair
-        valid = jnp.take(vcol.valid_mask(), order)
-        cnt = jax.ops.segment_sum(valid.astype(jnp.int64), seg_ids,
-                                  num_segments=num_segments,
-                                  indices_are_sorted=True)
+    vcols = [(table.columns[ci], op) for ci, op in aggs]
+    valids = [jnp.take(vcol.valid_mask(), order) for vcol, _ in vcols]
+    sums = _sorted_counts_and_sums(
+        vcols, order, valids,
+        _segment_ends(first_in_seg, true_segments, keys[0].size))
+    for (vcol, op), valid, (cnt, s) in zip(vcols, valids, sums):
+        out_dtype = _agg_out_dtype(vcol.dtype, op)
         if op == "count":
             out_cols.append(Column(dt.INT64, num_segments, data=cnt))
             continue
@@ -584,7 +714,7 @@ def _groupby_aggregate(
                     is_min=(op == "min")))
             continue
         out_cols.append(_segment_agg_fixed(vcol, order, valid, seg_ids,
-                                           num_segments, cnt, op))
+                                           num_segments, cnt, op, s=s))
     return Table(tuple(_shrink(c, live_groups) for c in out_cols))
 
 
@@ -641,13 +771,13 @@ def groupby_core(keys: List[Column], aggs: Sequence[Tuple[Column, str]],
     first_in_seg = nonzero_indices(boundary, num_segments)
     rep_rows = jnp.take(order, first_in_seg)
     out_cols = [gather(k, rep_rows) for k in keys]
-    for vcol, op in aggs:
-        valid = jnp.take(vcol.valid_mask(), order) & row_ok
-        cnt = jax.ops.segment_sum(valid.astype(jnp.int64), seg_c,
-                                  num_segments=num_segments,
-                                  indices_are_sorted=True)
+    valids = [jnp.take(vcol.valid_mask(), order) & row_ok
+              for vcol, _ in aggs]
+    sums = _sorted_counts_and_sums(
+        aggs, order, valids, _segment_ends(first_in_seg, seg_ids[-1] + 1, n))
+    for (vcol, op), valid, (cnt, s) in zip(aggs, valids, sums):
         out_cols.append(_segment_agg_fixed(vcol, order, valid, seg_c,
-                                           num_segments, cnt, op))
+                                           num_segments, cnt, op, s=s))
     return out_cols, live_groups, overflow
 
 
@@ -724,17 +854,23 @@ def groupby_direct_wide_core(key: jnp.ndarray, aggs, row_mask,
                              lo: int, span: int, num_slots: int,
                              live_agg):
     """Direct-slot groupby for a single int key with a WIDE span (up to
-    ~2^21 slots): one scatter-add per aggregate instead of the generic
-    core's n-row lexsort — the fused-plan path for q3-shaped groupbys
-    (many groups, integer sums). ``aggs``: (value i64[n] | None, op) with
-    op in sum/count (count ignores the value). ``live_agg``: index of a
-    sum aggregate whose per-row value stats prove > 0, making slot
-    liveness free (sum > 0); None adds a dedicated count scatter.
+    ~2^21 slots), with no n-row lexsort: the fused-plan path for
+    q3-shaped groupbys (many groups, integer sums) and for a literal key.
+    ``aggs``: (value i64[n] | None, op) with op in sum/count (count
+    ignores the value). ``live_agg``: index of a sum aggregate whose
+    per-row value stats prove > 0, making slot liveness free (sum > 0);
+    None adds a dedicated count.
+
+    With ``span == 1`` (a literal key, TPC-H q6) every kept row falls in
+    slot 0, so each aggregate is one masked reduction placed in slot 0.
+    A wider span scatter-adds each aggregate into its key's slot: the
+    keys are unsorted, so block totals do not apply.
 
     Slots stay in key order WITHOUT compaction — output slot s holds key
     ``lo + s`` and ``live_mask[s]`` marks real groups (the executor's
     mask-gather trim, or a downstream fused sort, orders them). ``bad``
-    re-checks the span claim on device (overflow semantics).
+    re-checks the span claim on device (overflow semantics); while it
+    holds, every kept row is in its key's slot, however it is summed.
 
     Returns ``(slot_keys i64[G], out_sums tuple, live_mask bool[G],
     live i32, bad bool)``."""
@@ -742,18 +878,24 @@ def groupby_direct_wide_core(key: jnp.ndarray, aggs, row_mask,
     bad = ~jnp.all((key >= lo) & (key < lo + span))
     keep = row_mask if row_mask is not None else jnp.ones((n,), dtype=bool)
     seg = jnp.clip(key - lo, 0, num_slots - 1).astype(jnp.int32)
+    slot0 = jnp.arange(num_slots) == 0
+
+    def slot_sums(contrib):
+        if span == 1:
+            _count_sums("block")
+            return jnp.where(slot0, jnp.sum(contrib, dtype=contrib.dtype),
+                             jnp.zeros((), contrib.dtype))
+        return _scatter_segment_sum(contrib, seg, num_slots)
+
     outs = []
     for val, op in aggs:
         if op == "count":
             contrib = keep.astype(jnp.int64)
         else:
             contrib = jnp.where(keep, val, jnp.int64(0))
-        outs.append(jax.ops.segment_sum(contrib, seg,
-                                        num_segments=num_slots))
+        outs.append(slot_sums(contrib))
     if live_agg is None:
-        cnt = jax.ops.segment_sum(keep.astype(jnp.int32), seg,
-                                  num_segments=num_slots)
-        live_mask = cnt > 0
+        live_mask = slot_sums(keep.astype(jnp.int32)) > 0
     else:
         live_mask = outs[live_agg] > 0
     slot_keys = jnp.arange(num_slots, dtype=jnp.int64) + lo

@@ -62,7 +62,8 @@ class PlanMetrics:
     _COUNTERS = ("plan_compiles", "plan_cache_hits", "plan_cache_misses",
                  "plan_executes", "plan_fallbacks", "plan_join_fallbacks",
                  "plan_overflows", "plan_oom_retries", "plan_oom_splits",
-                 "plan_oom_pieces", "plan_oom_spill_bytes")
+                 "plan_oom_pieces", "plan_oom_spill_bytes",
+                 "plan_block_sums", "plan_scatter_sums")
     _TIMES = ("compile_s", "execute_s", "prepare_s", "dispatch_s",
               "stage_s", "trim_s")
     RECENT = 512  # additions kept per time counter, for ``recent``

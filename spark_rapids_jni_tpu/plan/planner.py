@@ -32,9 +32,13 @@ Strategies:
                      one integer sum with per-row values proven in
                      (0, 2^48): packed-word chunked-scan accumulation.
           ``direct_wide``   single int key (possibly after FD reduction),
-                     span <= plan.groupby_wide_span, int sum/count aggs:
-                     one scatter-add per agg, no lexsort.
-          ``generic``       everything else: ops/groupby.groupby_core.
+                     span <= plan.groupby_wide_span, int sum/count aggs,
+                     no lexsort: one masked reduction per agg into slot
+                     0 when span == 1 (a literal key), else one
+                     scatter-add per agg.
+          ``generic``       everything else: ops/groupby.groupby_core,
+                     integer sums and counts from block totals over the
+                     sorted segments.
   Limit   ``topk``   Sort+Limit(k <= plan.topk_max) fuses into k
                      min-selection rounds; the Sort node is skipped.
 

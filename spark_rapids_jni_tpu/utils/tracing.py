@@ -50,6 +50,20 @@ span                what it covers                               counter
 fused program, each plan node's operations carry a
 ``plan.<i>.<Kind>[.<strategy>]`` name scope (``plan.head`` for the head);
 ``ProgramCache.op_scopes()`` maps the compiled instructions to them.
+
+Two ``plan_metrics`` counts say how a groupby's segment sums were built,
+one per sum taken (a value column's sum, a count of valid rows), where
+the sum is traced (once per compile in a fused program, once per call
+in the eager op):
+
+=====================  ================================================
+counter                segment sums built
+=====================  ================================================
+``plan_block_sums``    without a scatter: from block totals over sorted
+                       segments, or as one reduction into a single slot
+``plan_scatter_sums``  as a ``jax.ops.segment_sum`` scatter-add:
+                       unsorted keys, float sums, decimal limbs
+=====================  ================================================
 """
 
 from __future__ import annotations

@@ -57,3 +57,27 @@ def pytest_runtest_makereport(item, call):
               f"({getattr(err, 'brief', 'g++ failed')})")
     rep.outcome = "skipped"
     rep.longrepr = (str(item.fspath), item.location[1], f"Skipped: {reason}")
+
+
+# Files whose tests ``--dist loadfile`` hands out one by one instead of as
+# one unit. test_multichip.py runs every partition's ops eagerly on its own
+# virtual device, so each op compiles once per device and shape: its 27
+# tests take over 20 minutes in one process, longer than the rest of the
+# suite spread over six workers. Its only module fixture is a mesh handle.
+_SPLIT_FILES = frozenset({"tests/test_multichip.py"})
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    """Under ``--dist loadfile``, the file scheduler with each test of
+    ``_SPLIT_FILES`` a work unit of its own."""
+    if config.getvalue("dist") != "loadfile":
+        return None
+    from xdist.scheduler import LoadFileScheduling
+
+    class _Scheduler(LoadFileScheduling):
+        def _split_scope(self, nodeid):
+            path = nodeid.split("::", 1)[0]
+            return nodeid if path in _SPLIT_FILES else path
+
+    return _Scheduler(config, log)
