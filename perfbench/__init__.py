@@ -6,7 +6,9 @@ configuration, traffic mix or metric sits in a file of its own, found by
 the name ``BENCHMARK.json`` gives it:
 
 - ``configs/<config>.json``: the deployment (scale, rows per table,
-  chips, entry point, placement of the resident tables);
+  chips, entry point, placement of the tables: resident on one chip,
+  row-sharded over the mesh, or in Parquet files the engine reads inside
+  the window, with the writer's settings);
 - ``configs/<schema>/<query>.py``: the query's tables, its plan, its
   plain numpy reference and its lower-precision control;
 - ``traffic/<traffic>.json``: the query, its literals, the loop and the

@@ -4,6 +4,10 @@ Each ``<query>.py`` module holds what one query needs, so a later query
 is a new file:
 
 - ``generate(rows, seed)``: the input tables as lists of host arrays;
+- ``TABLES``: ``((table, column_names), ...)`` in ``generate``'s order,
+  the TPC-H names of each table and of its generated columns; the
+  ``parquet`` placement writes a file a table under these names, so
+  that the engine can prune columns by name;
 - ``WITH_STATS``: whether the tables carry advisory column statistics
   (as a Parquet footer would give them to the planner);
 - ``plan(**literals)``: the logical plan handed to the engine;

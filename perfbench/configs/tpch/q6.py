@@ -14,6 +14,7 @@ import numpy as np
 from . import gen
 
 WITH_STATS = False
+TABLES = (("lineitem", gen.LINEITEM_Q1),)
 LIMITS = {"wrong_values": 0}
 
 

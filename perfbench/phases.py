@@ -55,21 +55,22 @@ def traced_run(cell: spec.Cell, seed: int, seconds: float, devices: list,
     c0 = (compiles.n, compiles.seconds)
     q, cfg, lit = cell.query, cell.config, cell.traffic["literals"]
     host = q.generate(cfg["rows"], seed)
-    inputs = run.upload(host, q.WITH_STATS, cfg["placement"], devices)
-    serve = run.make_serve(getattr(engine, cfg["entry"]), q.plan(**lit),
-                           inputs, cfg.get("entry_kwargs", {}))
-    warm = loop.serve_once(serve, run.probe, -1)
-    if warm.error is not None:
-        raise RuntimeError(f"warm-up query failed: {warm.error}")
-    c1 = (compiles.n, compiles.seconds)
-    setup_s = time.perf_counter() - _T0
-    traffic = dict(cell.traffic)
-    if traced_queries is not None:
-        traffic["trace"] = {**traffic["trace"], "queries": traced_queries}
-    done, w0 = loop.run_window(traffic, serve, run.probe, seconds,
-                               run.Tracer(TRACE_DIR))
-    c2 = (compiles.n, compiles.seconds)
-    peak = run.peak_bytes(devices)
+    with run.placed(cell, seed, host, devices) as (inputs, _files):
+        serve = run.make_serve(getattr(engine, cfg["entry"]), q.plan(**lit),
+                               inputs, cfg.get("entry_kwargs", {}))
+        warm = loop.serve_once(serve, run.probe, -1)
+        if warm.error is not None:
+            raise RuntimeError(f"warm-up query failed: {warm.error}")
+        c1 = (compiles.n, compiles.seconds)
+        setup_s = time.perf_counter() - _T0
+        traffic = dict(cell.traffic)
+        if traced_queries is not None:
+            traffic["trace"] = {**traffic["trace"],
+                                "queries": traced_queries}
+        done, w0 = loop.run_window(traffic, serve, run.probe, seconds,
+                                   run.Tracer(TRACE_DIR))
+        c2 = (compiles.n, compiles.seconds)
+        peak = run.peak_bytes(devices)
     del inputs, serve, warm
     gc.collect()
 
@@ -78,7 +79,7 @@ def traced_run(cell: spec.Cell, seed: int, seconds: float, devices: list,
     path = trace.find_xplane(TRACE_DIR)
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    ids = [d.id for d in devices]
+    ids = [d.id for d in run.used_devices(cfg["placement"], devices)]
     tr = trace.reduce_profile(pd, device_ids=ids)
     att = attribution.reduce_profile(pd, scopes, fusions, device_ids=ids)
     if keep:
@@ -96,7 +97,7 @@ def traced_run(cell: spec.Cell, seed: int, seconds: float, devices: list,
 
     measured = [d for d in done if not d.traced] or done
     record = {
-        "chips": cell.chips, "peaks": peaks, "setup_s": setup_s,
+        "chips": len(ids), "peaks": peaks, "setup_s": setup_s,
         "elapsed_s": done[-1].end - w0,
         "latencies_s": [d.seconds for d in done if d.answer is not None],
         "completed": sum(d.answer is not None for d in done),

@@ -2,21 +2,26 @@
 
     python3 -m perfbench.run --workload q1_sf1 --seed 7 --seconds 45 --trace 0
 
-Set-up generates the cell's tables on the host from the seed, uploads
-them once and keeps them resident in HBM (row-sharded over the mesh
-where the configuration says so), then warms the cell's one program by
-running one query. The window runs the query back to back as the
-traffic file says. Afterwards the peak HBM is read, the tables are
-freed, the plain numpy reference is computed and every answer of the
-window is compared with it.
+Set-up generates the cell's tables on the host from the seed and places
+them as the configuration's ``placement`` says: ``device`` uploads them
+once and keeps them resident in HBM, ``row_sharded`` the same split by
+rows over the mesh, and ``parquet`` writes them to Parquet files and
+uploads nothing, so that each query of the window reads and decodes the
+files (``sources.py`` gives that contract). Set-up then warms the cell's
+one program by running one query. The window runs the query back to
+back as the traffic file says. Afterwards the peak HBM is read, the
+tables are freed or the files deleted, the plain numpy reference is
+computed and every answer of the window is compared with it.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics,
-or with ``--trace 1`` its per-layer metrics), ``device``, with
-``--trace 1`` a ``breakdown``, and last ``checks``: each number compared
-with its limit, which also end standard error. Without a TPU, with
-fewer chips than the cell asks for, or with a device kind missing from
-``peaks.json``, it prints no result and exits non-zero.
+or with ``--trace 1`` its per-layer metrics), ``device``, under the
+``parquet`` placement ``sources`` (each file's bytes, rows, row groups,
+codec and encodings per column), with ``--trace 1`` a ``breakdown``,
+and last ``checks``: each number compared with its limit, which also
+end standard error. Without a TPU, with fewer chips than the cell asks
+for, or with a device kind missing from ``peaks.json``, it prints no
+result and exits non-zero.
 """
 
 from __future__ import annotations
@@ -26,16 +31,18 @@ import time
 _T0 = time.perf_counter()  # set-up is timed from the runner's first line
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
-from typing import Any, Dict, List, Optional, Sequence, Tuple  # noqa: E402
+from typing import (Any, Dict, Iterator, List, Optional, Sequence,  # noqa: E402
+                    Tuple)
 
 import numpy as np  # noqa: E402
 
-from perfbench import loop, spec, trace  # noqa: E402
+from perfbench import loop, sources, spec, trace  # noqa: E402
 
 CACHE_DIR = os.path.join(spec.ROOT, ".srjt_cache", "xla")
 TRACE_DIR = os.path.join(spec.ROOT, ".srjt_cache", "perfbench", "trace")
@@ -117,6 +124,14 @@ def probe() -> Tuple[float, Dict[str, int]]:
     return float(p.get("execute_s", 0.0)), faults
 
 
+def used_devices(placement: str, devices: list) -> list:
+    """The chips that hold the cell's tables and run its queries: every
+    one under ``row_sharded``, else the first. A cell may ask for more
+    chips than it uses, to hold a whole host and its CPU cores alone; the
+    trace's busy time and the roofline count only the chips used."""
+    return devices if placement == "row_sharded" else devices[:1]
+
+
 def upload(host: Sequence[Sequence[np.ndarray]], with_stats: bool,
            placement: str, devices: list) -> list:
     """Host arrays -> resident device Tables, in one bulk transfer.
@@ -154,8 +169,27 @@ def upload(host: Sequence[Sequence[np.ndarray]], with_stats: bool,
     return tables
 
 
+@contextlib.contextmanager
+def placed(cell: spec.Cell, seed: int, host: Sequence[Sequence[np.ndarray]],
+           devices: list) -> Iterator[Tuple[Any, Optional[Dict[str, Any]]]]:
+    """The engine's inputs for one run and, under the ``parquet``
+    placement, what each file holds: resident Tables (``upload``), or the
+    paths of Parquet files that are deleted when the context closes
+    (``sources.parquet_files``)."""
+    cfg = cell.config
+    if cfg["placement"] != "parquet":
+        yield upload(host, cell.query.WITH_STATS, cfg["placement"],
+                     devices), None
+        return
+    with sources.parquet_files(cell.name, seed, cell.query, host,
+                               cfg["parquet"]) as files:
+        yield files.paths, files.described
+
+
 def make_serve(entry, plan, inputs, kwargs):
-    """One query through the engine's entry, its answer copied to host."""
+    """One query through the engine's entry, its answer copied to host.
+    ``inputs`` are resident Tables or, under the ``parquet`` placement,
+    file paths; one is handed over alone, several as a list."""
     import jax
     arg = inputs[0] if len(inputs) == 1 else inputs
 
@@ -240,20 +274,22 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     compiles = CompileCounter.get()
     c0 = (compiles.n, compiles.seconds)
     q, cfg, lit = cell.query, cell.config, cell.traffic["literals"]
+    used = used_devices(cfg["placement"], devices)
     host = q.generate(cfg["rows"], seed)
-    inputs = upload(host, q.WITH_STATS, cfg["placement"], devices)
-    serve = make_serve(getattr(engine, cfg["entry"]), q.plan(**lit), inputs,
-                       cfg.get("entry_kwargs", {}))
-    warm = loop.serve_once(serve, probe, -1)
-    if warm.error is not None:
-        raise RuntimeError(f"warm-up query failed: {warm.error}")
-    c1 = (compiles.n, compiles.seconds)
-    setup_s = time.perf_counter() - t0
+    with placed(cell, seed, host, devices) as (inputs, files):
+        serve = make_serve(getattr(engine, cfg["entry"]), q.plan(**lit),
+                           inputs, cfg.get("entry_kwargs", {}))
+        warm = loop.serve_once(serve, probe, -1)
+        if warm.error is not None:
+            raise RuntimeError(f"warm-up query failed: {warm.error}")
+        c1 = (compiles.n, compiles.seconds)
+        setup_s = time.perf_counter() - t0
 
-    tracer = Tracer(TRACE_DIR) if traced else None
-    done, w0 = loop.run_window(cell.traffic, serve, probe, seconds, tracer)
-    c2 = (compiles.n, compiles.seconds)
-    peak = peak_bytes(devices)
+        tracer = Tracer(TRACE_DIR) if traced else None
+        done, w0 = loop.run_window(cell.traffic, serve, probe, seconds,
+                                   tracer)
+        c2 = (compiles.n, compiles.seconds)
+        peak = peak_bytes(devices)
     del inputs, serve, warm
     gc.collect()
 
@@ -262,13 +298,13 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         path = trace.find_xplane(TRACE_DIR)
         if path is None:
             raise RuntimeError(f"the profiler wrote no trace under {TRACE_DIR}")
-        tr = trace.reduce_file(path, device_ids=[d.id for d in devices])
+        tr = trace.reduce_file(path, device_ids=[d.id for d in used])
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
     checks = check_answers(q, done, q.reference(host, **lit))
 
     measured = [d for d in done if not d.traced] or done
     record = {
-        "chips": cell.chips, "peaks": peaks, "setup_s": setup_s,
+        "chips": len(used), "peaks": peaks, "setup_s": setup_s,
         "elapsed_s": done[-1].end - w0,
         "latencies_s": [d.seconds for d in done if d.answer is not None],
         "completed": sum(d.answer is not None for d in done),
@@ -296,6 +332,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         "metrics": metrics,
         "device": device,
     }
+    if files is not None:
+        result["sources"] = files
     if tr is not None:
         device.update(busy_s=tr["busy_s"], window_s=tr["window_s"])
         result["breakdown"] = {"device_ops": tr["device_ops"],

@@ -16,6 +16,9 @@ from typing import Any, Callable, Dict, List, Optional
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HERE = os.path.join(ROOT, "perfbench")
+PLACEMENTS = ("device", "row_sharded", "parquet")
+PARQUET_KEYS = ("compression", "dictionary", "data_page_version",
+                "row_group_bytes", "data_page_bytes")
 
 
 class SpecError(ValueError):
@@ -80,10 +83,51 @@ def load_cell(name: str) -> Cell:
                         f"{config['chips']} chips, the cell asks {w['chips']}")
     query = importlib.import_module(
         f"perfbench.configs.{config['schema']}.{traffic['query']}")
+    check_placement(config, query)
     return Cell(name=name, chips=w["chips"], config=config, traffic=traffic,
                 query=query,
                 end_to_end=_metrics(bench["end_to_end"], name),
                 per_layer=_metrics(bench["per_layer"], name))
+
+
+def check_placement(config: Dict[str, Any], query: ModuleType) -> None:
+    """The configuration's ``placement`` is known; a ``parquet`` block
+    goes with the ``parquet`` placement and no other, holds exactly
+    ``PARQUET_KEYS``, and the query names its tables' columns."""
+    where = f"config {config.get('name')!r}"
+    placement = config.get("placement")
+    if placement not in PLACEMENTS:
+        raise SpecError(f"{where}: unknown placement {placement!r}; "
+                        f"placements: {PLACEMENTS}")
+    block = config.get("parquet")
+    if placement != "parquet":
+        if block is not None:
+            raise SpecError(f"{where}: a parquet block under the "
+                            f"{placement!r} placement")
+        return
+    if not isinstance(block, dict):
+        raise SpecError(f"{where}: the parquet placement needs a parquet "
+                        f"block with {PARQUET_KEYS}")
+    if set(block) != set(PARQUET_KEYS):
+        raise SpecError(
+            f"{where}: parquet block keys {sorted(block)}; missing "
+            f"{sorted(set(PARQUET_KEYS) - set(block))}, unknown "
+            f"{sorted(set(block) - set(PARQUET_KEYS))}")
+    if not (isinstance(block["compression"], str)
+            and isinstance(block["dictionary"], bool)
+            and block["data_page_version"] in ("1.0", "2.0")
+            and all(type(block[k]) is int and block[k] > 0
+                    for k in ("row_group_bytes", "data_page_bytes"))):
+        raise SpecError(f"{where}: parquet block {block}: compression is a "
+                        f"codec name, dictionary a bool, data_page_version "
+                        f"'1.0' or '2.0', the byte sizes positive integers")
+    tables = getattr(query, "TABLES", None)
+    if not (isinstance(tables, tuple) and tables and all(
+            isinstance(t, tuple) and len(t) == 2 and isinstance(t[0], str)
+            and isinstance(t[1], tuple) and t[1] for t in tables)):
+        raise SpecError(f"{where}: query {query.__name__} does not name its "
+                        f"tables' columns as TABLES = ((table, column_names),"
+                        f" ...)")
 
 
 def peaks(kind: str) -> Dict[str, Any]:

@@ -90,6 +90,17 @@ def test_refuses_too_few_chips(monkeypatch):
         run.require_devices(4)
 
 
+def test_a_cell_uses_the_chips_its_placement_fills():
+    """A cell may hold a whole host for steadiness and fill one chip of
+    it: busy time and the roofline then count that chip alone."""
+    devs = ["chip0", "chip1", "chip2", "chip3"]
+    assert run.used_devices("device", devs) == ["chip0"]
+    assert run.used_devices("parquet", devs) == ["chip0"]
+    assert run.used_devices("row_sharded", devs) == devs
+    q6 = spec.load_cell("q6_sf10")
+    assert q6.chips == 4 and q6.config["placement"] == "device"
+
+
 def test_refuses_a_device_kind_without_peaks(monkeypatch):
     _devices(monkeypatch, "TPU v99", 1)
     with pytest.raises(run.NoDevice, match="peaks.json"):
